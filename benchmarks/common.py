@@ -1,6 +1,10 @@
 """Shared benchmark harness: the traced target programs (the paper's BT/CG/
-MG/... analogs are our framework's own distributed step functions), run in a
-subprocess with a forced 8-device host platform."""
+MG/... analogs are our framework's own distributed step functions).
+
+The CPU benchmarks force an 8-device host platform (:func:`ensure_devices`,
+called by each benchmark module before JAX starts).  :func:`stencil_program`
+builds its mesh from whatever devices exist, so ``chip_smoke.py --chips 4``
+runs it on four real chips without any forcing."""
 from __future__ import annotations
 
 import os
@@ -15,12 +19,13 @@ def ensure_devices():
 
 def stencil_program(n: int = 8, length: int = 12):
     """2D-stencil analog (paper Fig. 2 / NPB MG-flavored): halo ppermutes +
-    compute + global psum inside a scan."""
-    ensure_devices()
+    compute + global psum inside a scan.  ``n`` must not exceed the
+    device count."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.compat import make_mesh
 
     mesh = make_mesh((n,), ("x",))
 
@@ -48,11 +53,11 @@ def stencil_program(n: int = 8, length: int = 12):
 def allreduce_train_program(n: int = 8, layers: int = 6):
     """Data-parallel training analog (NPB CG-flavored): per-layer compute +
     gradient psum, explicit shard_map DP."""
-    ensure_devices()
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.compat import make_mesh
 
     mesh = make_mesh((n,), ("x",))
 
@@ -73,7 +78,6 @@ def allreduce_train_program(n: int = 8, layers: int = 6):
 def pipeline_traces(n_ranks: int = 8, microbatches: int = 12):
     """Pipeline-parallel schedule (heterogeneous per-rank mains — the case
     that exercises Algorithm 1's clustering).  Host-level TraceSession."""
-    ensure_devices()
     import jax.numpy as jnp
     from repro.core.events import CommEvent, ComputeEvent
     from repro.core.tracer import TraceSession, compute_cost

@@ -9,7 +9,11 @@ from __future__ import annotations
 
 import time
 
-from benchmarks.common import PROGRAMS, exec_size_cols, pipeline_traces
+from benchmarks.common import (
+    PROGRAMS, ensure_devices, exec_size_cols, pipeline_traces,
+)
+
+ensure_devices()
 
 
 def run() -> list[dict]:

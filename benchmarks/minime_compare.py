@@ -8,7 +8,9 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.common import PROGRAMS
+from benchmarks.common import PROGRAMS, ensure_devices
+
+ensure_devices()
 
 
 def _collect(fn, args, axes):

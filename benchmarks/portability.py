@@ -23,6 +23,8 @@ import numpy as np
 
 from benchmarks.common import PROGRAMS, ensure_devices
 
+ensure_devices()
+
 #: reduced-zoo shape for the cross-chip rows (matches the fidelity tier)
 CROSS_CHIP_KWARGS = {"n_ranks": 4, "steps": 2}
 
@@ -81,7 +83,6 @@ def _walker_err(proxy, pred) -> float:
 
 def cross_chip_rows(scenarios=None, **kwargs) -> list[dict]:
     """Predicted profiles for the (reduced) zoo on every known chip."""
-    ensure_devices()
     from repro.core.portability import REFERENCE_CHIP, predict_all
     from repro.core.synthesize import synthesize_corpus
     kwargs = {**CROSS_CHIP_KWARGS, **kwargs}

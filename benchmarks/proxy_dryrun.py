@@ -8,11 +8,13 @@ from __future__ import annotations
 
 
 def run() -> list[dict]:
-    from benchmarks.common import PROGRAMS
+    from benchmarks.common import PROGRAMS, ensure_devices
+    ensure_devices()
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.compat import make_mesh
     from repro.core.synthesize import synthesize
     from repro.core.replay import init_replay_state
     from repro.launch.hlo_cost import analyze

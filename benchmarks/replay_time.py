@@ -35,7 +35,9 @@ import time
 
 import numpy as np
 
-from benchmarks.common import PROGRAMS
+from benchmarks.common import PROGRAMS, ensure_devices
+
+ensure_devices()
 
 _BATCH_RANKS = 16
 

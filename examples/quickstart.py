@@ -12,9 +12,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import jax                     # noqa: E402
 import jax.numpy as jnp        # noqa: E402
+from jax import shard_map      # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro.compat import make_mesh, shard_map  # noqa: E402
+from repro.compat import make_mesh  # noqa: E402
 from repro.core.synthesize import synthesize  # noqa: E402
 
 N = 8

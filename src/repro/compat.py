@@ -1,134 +1,32 @@
-"""JAX cross-version compatibility shims — the single home for API drift.
+"""JAX API drift — the single home for it.
 
-The repo pins no exact JAX version; the container currently ships 0.4.37
-while much of the code was written against the ≥ 0.5 surface.  Every
-version-sensitive call goes through this module so future drift has one
-place to land:
+The repo targets the installed JAX (0.9.0, the same on the TPU host).
+Two things here depend on what that JAX offers:
 
-* :func:`make_mesh` — ``jax.make_mesh`` grew an ``axis_types`` kwarg (and
-  ``jax.sharding.AxisType``) after 0.4.x; we pass it only when supported.
-* :func:`shard_map` — ``jax.shard_map`` is ``jax.experimental.shard_map``
-  on 0.4.x, and the ``check_vma`` kwarg used to be spelled ``check_rep``.
-* :func:`tree_flatten_with_path` — ``jax.tree.flatten_with_path`` is
-  missing on 0.4.x; ``jax.tree_util.tree_flatten_with_path`` exists on both.
-* :func:`ensure_batching_rules` — 0.4.x lacks the ``optimization_barrier``
-  batching rule (added upstream later); the batched replay engine vmaps
-  over a rank axis and needs it.  Registered once at import.
+* :func:`make_mesh` — ``jax.make_mesh`` defaults to explicit-sharding axis
+  types; the repo's meshes are GSPMD meshes, so every axis is requested as
+  ``AxisType.Auto``.
 * :func:`collective_batching_audit` — the mesh-sharded replay engine vmaps
   a rank axis through *real* collectives inside ``shard_map``; this audits
   that every collective primitive the replay emits has a batching rule on
-  the running JAX.  On floor 0.4.x all of them do (``optimization_barrier``
-  was the only gap, patched above) — the audit is the guard that keeps it
-  that way as JAX moves.
+  the running JAX.
 
-Policy: shims are feature-detected (``inspect.signature`` / ``getattr``),
-never version-compared, so they keep working as JAX moves.
+``shard_map`` and pytree paths are called straight from ``jax``
+(``jax.shard_map``, ``jax.tree.flatten_with_path``).  Primitive *names* the
+jaxpr walker must know live in :mod:`repro.core.metrics`.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Any, Callable
-
 import jax
-
-# ---------------------------------------------------------------------------
-# mesh construction
-# ---------------------------------------------------------------------------
-
-_MAKE_MESH_PARAMS = frozenset(inspect.signature(jax.make_mesh).parameters)
+from jax.sharding import AxisType
 
 
-def default_axis_types(n_axes: int):
-    """``(AxisType.Auto,) * n`` when the enum exists, else None."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n_axes
-
-
-def make_mesh(axis_shapes, axis_names, *, axis_types: Any = "auto",
-              devices=None):
-    """Version-safe ``jax.make_mesh``.
-
-    ``axis_types="auto"`` (the default) requests ``AxisType.Auto`` for every
-    axis when the running JAX supports axis types, and silently omits the
-    argument when it does not — which is exactly the old behaviour.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if "axis_types" in _MAKE_MESH_PARAMS:
-        if axis_types == "auto":
-            axis_types = default_axis_types(len(tuple(axis_names)))
-        if axis_types is not None:
-            kwargs["axis_types"] = axis_types
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-_SHARD_MAP_IMPL: Callable = getattr(jax, "shard_map", None)
-if _SHARD_MAP_IMPL is None:  # 0.4.x
-    from jax.experimental.shard_map import shard_map as _SHARD_MAP_IMPL
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_SHARD_MAP_IMPL).parameters)
-
-
-def shard_map(f: Callable, *, mesh, in_specs, out_specs,
-              check_vma: bool | None = None, **kwargs):
-    """Version-safe ``shard_map``: maps ``check_vma`` to ``check_rep`` on
-    older JAX (same semantics: per-output replication checking)."""
-    if check_vma is not None:
-        if "check_vma" in _SHARD_MAP_PARAMS:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in _SHARD_MAP_PARAMS:
-            kwargs["check_rep"] = check_vma
-    return _SHARD_MAP_IMPL(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
-
-
-# ---------------------------------------------------------------------------
-# pytree paths
-# ---------------------------------------------------------------------------
-
-
-def tree_flatten_with_path(tree, is_leaf=None):
-    """``jax.tree.flatten_with_path`` on new JAX, ``jax.tree_util`` on old."""
-    fwp = getattr(jax.tree, "flatten_with_path", None)
-    if fwp is not None:
-        return fwp(tree, is_leaf=is_leaf)
-    return jax.tree_util.tree_flatten_with_path(tree, is_leaf)
-
-
-# ---------------------------------------------------------------------------
-# missing batching rules (vmap support for the batched replay engine)
-# ---------------------------------------------------------------------------
-
-_BATCHING_DONE = False
-
-
-def ensure_batching_rules() -> None:
-    """Register the ``optimization_barrier`` batching rule when missing.
-
-    The rule is the identity on batch dims (the barrier is semantically the
-    identity function); upstream JAX added the same rule after 0.4.x.
-    Idempotent and a no-op on versions that already have it.
-    """
-    global _BATCHING_DONE
-    if _BATCHING_DONE:
-        return
-    _BATCHING_DONE = True
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-    except ImportError:  # pragma: no cover - internals moved; newer JAX has the rule
-        return
-    if optimization_barrier_p not in batching.primitive_batchers:
-        def _barrier_batch_rule(args, dims):
-            return optimization_barrier_p.bind(*args), dims
-
-        batching.primitive_batchers[optimization_barrier_p] = _barrier_batch_rule
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    axis_names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 #: lax collective primitives the replay comm backends can emit (DeviceComm
@@ -146,33 +44,24 @@ def collective_batching_audit() -> list[str]:
     ``vmap``-s them through ``DeviceComm`` inside ``shard_map``; that is
     only sound when every collective primitive has a batching rule (the
     rank axis is then folded into the real collective).  Returns the names
-    that lack one — empty on every supported JAX, asserted by tests; a
-    future JAX that drops a rule fails loudly there instead of silently
-    falling back to a per-rank loop.
+    that lack one — empty on the installed JAX, asserted by tests; a JAX
+    that drops a rule fails loudly there instead of silently falling back
+    to a per-rank loop.
 
     Deliberately pessimistic: a primitive that cannot be *found* (public
     ``jax.lax.<name>_p`` first, then the ``jax._src.lax.parallel``
     internals) is reported as missing too — "internals moved" must surface
-    in the audit test, not hollow it out.
+    in the audit test, not hollow it out.  Collective rules live in
+    ``batching.fancy_primitive_batchers`` (``primitive_batchers`` is a
+    write-only proxy).
     """
     import jax.lax
+    from jax._src.lax import parallel as _par
     from jax.interpreters import batching
-    try:
-        from jax._src.lax import parallel as _par
-    except ImportError:  # pragma: no cover - internals moved
-        _par = None
-    registries = []
-    for reg_name in ("primitive_batchers", "fancy_primitive_batchers"):
-        reg = getattr(batching, reg_name, None)
-        if isinstance(reg, dict):        # axis_primitive_batchers is a
-            registries.append(reg)       # write-only proxy — skip non-dicts
+    registry = batching.fancy_primitive_batchers
     missing = []
     for name in _REPLAY_COLLECTIVE_PRIMS:
-        prim = getattr(jax.lax, f"{name}_p",
-                       getattr(_par, f"{name}_p", None) if _par else None)
-        if prim is None or not any(prim in reg for reg in registries):
+        prim = getattr(jax.lax, f"{name}_p", getattr(_par, f"{name}_p", None))
+        if prim is None or prim not in registry:
             missing.append(name)
     return missing
-
-
-ensure_batching_rules()

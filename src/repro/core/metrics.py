@@ -32,9 +32,11 @@ TRANSCENDENTAL_PRIMS = {
     "rsqrt", "sqrt", "cbrt", "digamma", "lgamma", "regularized_incomplete_beta",
 }
 
-#: irregular-address primitives (the L1_DCM analog)
-GATHER_PRIMS = {"gather", "scatter", "scatter_add", "scatter_mul", "scatter_min",
-                "scatter_max", "dynamic_slice", "dynamic_update_slice",
+#: irregular-address primitives (the L1_DCM analog).  The combining
+#: scatters (``scatter-add``/``-mul``/``-min``/``-max``) are not listed: they
+#: are costed as generic elementwise, the convention the fidelity baseline
+#: was recorded under (ROADMAP 2.4).
+GATHER_PRIMS = {"gather", "scatter", "dynamic_slice", "dynamic_update_slice",
                 "take", "take_along_axis", "argsort", "sort", "top_k"}
 
 #: primitives that move data without arithmetic (count bytes only)
@@ -45,12 +47,11 @@ DATA_MOVEMENT_PRIMS = {
     "pvary", "sharding_constraint", "reshard",
 }
 
-#: zero-cost bookkeeping primitives.  ``pbroadcast`` is the pre-0.5 spelling
-#: of ``pvary`` — the replication marker shard_map's check_rep machinery
-#: inserts after collectives; it lowers to a no-op and must not be recorded
-#: as a communication event (version drift handled like repro.compat).
+#: zero-cost bookkeeping primitives (``pvary`` is the replication marker
+#: shard_map's check_vma machinery inserts; it lowers to a no-op and must
+#: not be recorded as a communication event).
 FREE_PRIMS = {
-    "stop_gradient", "axis_index", "sharding_cast", "pvary", "pbroadcast",
+    "stop_gradient", "axis_index", "sharding_cast", "pvary",
     "symbolic_zeros", "empty", "debug_callback", "name",
     "optimization_barrier",
 }
@@ -69,13 +70,35 @@ COLLECTIVE_PRIMS = {
     "ppermute": "ppermute",
 }
 
-#: higher-order primitives carrying sub-jaxprs that the walker must enter
-HIGHER_ORDER_PRIMS = {
-    "pjit", "closed_call", "core_call", "remat", "checkpoint", "remat2",
-    "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
-    "custom_jvp_call_jaxpr", "shard_map", "scan", "while", "cond",
-    "custom_lin", "custom_transpose_call",
+#: call-like primitives whose body is ``params["jaxpr"]`` (a nested
+#: ``jax.jit`` is ``jit``, ``jax.checkpoint`` is ``remat2``)
+CALL_PRIMS = {"jit", "closed_call", "remat2"}
+
+#: custom-derivative primitives whose primal body is ``params["call_jaxpr"]``
+CUSTOM_DIFF_PRIMS = {"custom_jvp_call", "custom_vjp_call"}
+
+#: every primitive carrying sub-jaxprs that the walker enters.  A primitive
+#: with a sub-jaxpr that is not listed here raises in :func:`eqn_cost`:
+#: costing it as one elementwise op would drop its collectives silently.
+HIGHER_ORDER_PRIMS = CALL_PRIMS | CUSTOM_DIFF_PRIMS | {
+    "shard_map", "scan", "while", "cond",
 }
+
+
+#: sub-jaxpr params that are scalar combiners, not program bodies
+COMBINER_PARAMS = {"update_jaxpr"}
+
+
+def _has_sub_jaxpr(eqn) -> bool:
+    for k, v in eqn.params.items():
+        if k in COMBINER_PARAMS:
+            continue
+        if hasattr(v, "eqns") or hasattr(v, "jaxpr"):
+            return True
+        if isinstance(v, (tuple, list)) and any(
+                hasattr(b, "eqns") or hasattr(b, "jaxpr") for b in v):
+            return True
+    return False
 
 
 def _aval_size(aval) -> int:
@@ -160,6 +183,10 @@ def eqn_cost(eqn) -> np.ndarray:
         in_elems = sum(_aval_size(v.aval) for v in eqn.invars
                        if hasattr(getattr(v, "aval", None), "shape"))
         c[I_VPU] = in_elems
+    elif _has_sub_jaxpr(eqn):
+        raise NotImplementedError(
+            f"jaxpr walker: primitive {name!r} carries a sub-jaxpr the walker "
+            "does not know; add it to metrics.HIGHER_ORDER_PRIMS and walk it")
     else:
         # generic elementwise (add/mul/select/compare/min/max/...)
         c[I_VPU] = out_elems

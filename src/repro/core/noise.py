@@ -180,8 +180,7 @@ def attach(st: dict, key) -> dict:
     """Return a copy of a replay state with the noise leaves attached.
 
     ``key`` must be a raw ``uint32[2]`` PRNG key (not a typed key array)
-    so the leaves stay plain arrays under ``shard_map``/``tree`` on the
-    JAX 0.4.x floor.
+    so the leaves stay plain arrays under ``shard_map``/``tree``.
     """
     import jax.numpy as jnp
 

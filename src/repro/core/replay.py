@@ -51,7 +51,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec
 
-from repro import compat  # noqa: F401  (registers vmap rules on old JAX)
+from repro import compat
 from repro.core import blocks
 from repro.core import noise as noise_mod
 from repro.core import proxy_search
@@ -445,6 +445,21 @@ class ProxyProgram:
                                int(np.asarray(mesh.devices).size),
                                share_unit_groups=share_unit_groups)
 
+    def mesh_comm_events(self, mesh, ranks: Sequence[int] | None = None,
+                         ) -> dict[int, list]:
+        """``{rank: [CommEvent, ...]}``: the collectives of the exact
+        executable the mesh sweep dispatches for each rank's group, read
+        by the jaxpr walker (one walk per placed group; exact-cond mode
+        resolves the program tables' switch dispatch)."""
+        st = jax.eval_shape(lambda: init_replay_state(self.module))
+        out: dict[int, list] = {}
+        for pl in self.mesh_sweep_plan(mesh, ranks):
+            fn = self._fn_for_group_mesh(pl.sig, pl.ranks[0], None, pl, mesh)
+            events = trace_fn(fn, st, exact_cond=True).comm_events()
+            for r in pl.ranks:
+                out[r] = events
+        return out
+
     def _submesh_for(self, mesh, placement: GroupPlacement):
         devs = list(np.asarray(mesh.devices).flat)
         # keyed by the actual devices, not id(mesh): two Mesh objects over
@@ -509,7 +524,7 @@ class ProxyProgram:
                     return mod.run_rank(st, comm, rep_rank)
                 return jax.vmap(lambda s: mod.run_rank(s, comm, rep_rank))(st)
 
-            fn = jax.jit(compat.shard_map(
+            fn = jax.jit(jax.shard_map(
                 traced, mesh=submesh, in_specs=(spec,), out_specs=spec,
                 check_vma=False))
             self._compiled_batched[key] = fn

@@ -34,26 +34,16 @@ from typing import Any, Callable, Iterable, Sequence
 
 import jax
 import numpy as np
+from jax.extend.core import Literal as _Literal
 
 from repro.core.events import (
     CommEvent, ComputeEvent, Event, N_METRICS, encode_relative_perm, is_comm,
 )
 from repro.core.metrics import (
-    COLLECTIVE_PRIMS, I_SCAN, collective_event_info, eqn_cost,
+    CALL_PRIMS, COLLECTIVE_PRIMS, CUSTOM_DIFF_PRIMS, HIGHER_ORDER_PRIMS,
+    I_SCAN, collective_event_info, eqn_cost,
 )
 
-try:  # jax >= 0.4.x exposes Literal via jax.extend; older via jax.core
-    from jax.extend.core import Literal as _Literal
-except ImportError:  # pragma: no cover - old JAX fallback
-    from jax.core import Literal as _Literal
-
-#: primitives never constant-folded by the exact walker: higher-order (their
-#: sub-jaxprs are walked structurally) and anything with host side effects
-_NO_FOLD_PRIMS = frozenset({
-    "scan", "while", "cond", "pjit", "closed_call", "core_call", "custom_lin",
-    "remat", "remat2", "checkpoint", "shard_map", "custom_jvp_call",
-    "custom_vjp_call", "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
-})
 _FOLD_SIZE_CAP = 1 << 16   # skip folding on large operands (opcode arrays ok)
 
 
@@ -197,7 +187,7 @@ class JaxprWalker:
         casts), not replayed workload — costing them would break δ̄ parity
         with the unrolled reference, which has no dispatch machinery."""
         name = eqn.primitive.name
-        if name in _NO_FOLD_PRIMS or name in COLLECTIVE_PRIMS \
+        if name in HIGHER_ORDER_PRIMS or name in COLLECTIVE_PRIMS \
                 or "callback" in name:
             return False
         for v in eqn.params.values():
@@ -224,17 +214,11 @@ class JaxprWalker:
         if name in COLLECTIVE_PRIMS:
             self._emit_comm(eqn)
             return
-        if name in ("pjit", "closed_call", "core_call", "custom_lin"):
+        if name in CALL_PRIMS:
             self._walk_sub(eqn.params["jaxpr"], eqn.invars, env)
             return
-        if name in ("remat2", "remat", "checkpoint"):
-            self._walk_sub(eqn.params["jaxpr"], eqn.invars, env)
-            return
-        if name in ("custom_jvp_call", "custom_vjp_call", "custom_jvp_call_jaxpr",
-                    "custom_vjp_call_jaxpr"):
-            inner = eqn.params.get("call_jaxpr", eqn.params.get("fun_jaxpr"))
-            if inner is not None:
-                self.walk(inner)
+        if name in CUSTOM_DIFF_PRIMS:
+            self.walk(eqn.params["call_jaxpr"])
             return
         if name == "shard_map":
             mesh = eqn.params.get("mesh")
@@ -242,7 +226,7 @@ class JaxprWalker:
                 for ax, sz in zip(mesh.axis_names, mesh.shape.values()
                                   if hasattr(mesh.shape, "values") else mesh.shape):
                     self.axis_sizes[str(ax)] = int(sz)
-            self.walk(eqn.params["jaxpr"])
+            self._walk_sub(eqn.params["jaxpr"], eqn.invars, env)
             return
         if name == "scan":
             self._walk_scan(eqn, env)

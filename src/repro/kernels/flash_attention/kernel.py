@@ -72,7 +72,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float,
 
 def flash_fwd_pallas(q, k, v, *, causal: bool = True,
                      window: int | None = None, cq: int = 128, ck: int = 128,
-                     interpret: bool = True):
+                     interpret: bool = False):
     """q: (bh, s, d); k/v: (bh, t, d) — KV already expanded to q heads.
 
     Returns (bh, s, d).  ``interpret=True`` runs the kernel body in Python

@@ -35,7 +35,7 @@ def _mxu_iter_kernel(a_ref, b_ref, o_ref, *, reps: int):
     o_ref[...] = jax.lax.fori_loop(0, reps, body, a_ref[...])
 
 
-def mxu_pallas(a, b, reps: int, *, interpret: bool = True):
+def mxu_pallas(a, b, reps: int, *, interpret: bool = False):
     """a, b: (128, 128) bf16; returns a after ``reps`` MXU turns."""
     kern = functools.partial(_mxu_iter_kernel, reps=reps)
     return pl.pallas_call(
@@ -55,7 +55,7 @@ def _stream_iter_kernel(v_ref, o_ref, *, reps: int):
     o_ref[...] = jax.lax.fori_loop(0, reps, body, v_ref[...])
 
 
-def stream_pallas(v, reps: int, *, interpret: bool = True):
+def stream_pallas(v, reps: int, *, interpret: bool = False):
     """v: (n,) f32 with n a multiple of 1024; tiled streaming update."""
     n = v.shape[0]
     assert n % TILE == 0, n

@@ -1,8 +1,9 @@
 """jit wrapper mapping the model's SSD layout onto the Pallas kernel,
-with head-slab splitting to bound VMEM (r per slab ≤ 8)."""
+with head-slab splitting to bound VMEM (r per slab ≤ 8).  ``interpret=True``
+runs the kernel body in the Pallas interpreter (CPU tests); the default
+compiles it for the TPU."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.ssd.kernel import ssd_diag_pallas
@@ -10,18 +11,12 @@ from repro.kernels.ssd.kernel import ssd_diag_pallas
 _MAX_R = 8
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def ssd_diag_block(xc, dtc, cum, bc, cc, r: int,
-                   interpret: bool | None = None):
+                   interpret: bool = False):
     """Model layout: xc (b,c,q,h,p), dtc/cum (b,c,q,h), bc/cc (b,c,q,g,n)
     with h = g·r.  Returns y_diag (b,c,q,h,p)."""
     b, c, q, h, p = xc.shape
     g = bc.shape[3]
-    if interpret is None:
-        interpret = not _on_tpu()
     xg = xc.reshape(b, c, q, g, r, p)
     dtg = dtc.reshape(b, c, q, g, r)
     cumg = cum.reshape(b, c, q, g, r)

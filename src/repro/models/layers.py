@@ -8,12 +8,12 @@ turn a Param tree into concrete arrays or ShapeDtypeStructs.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.sharding.partition import constraint
 
@@ -43,21 +43,35 @@ def logical_axes(tree):
     return jax.tree.map(lambda p: p.axes, tree, is_leaf=is_param)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _init_leaf(key, shape: tuple[int, ...], dtype: str, scale: float):
+    dt = jnp.dtype(dtype)
+    if scale == 0.0:
+        return jnp.zeros(shape, dt)
+    if len(shape) <= 1:
+        return jnp.full(shape, scale, dt)
+    std = scale / math.sqrt(max(shape[-2], 1))
+
+    def draw(k, shp):
+        return (jax.random.normal(k, shp, jnp.float32) * std).astype(dt)
+
+    if len(shape) == 2:
+        return draw(key, shape)
+    # stacked leaves (layers, ...): one slice at a time, so the random bits
+    # of a full-width leaf never sit on the device at once
+    return jax.lax.map(lambda k: draw(k, shape[1:]),
+                       jax.random.split(key, shape[0]))
+
+
 def materialize(tree, seed: int = 0):
-    """Concrete init (reduced smoke configs only; full configs stay abstract)."""
+    """Concrete init on the default device, drawn from ``seed`` leaf by leaf
+    in each leaf's dtype: zeros for ``scale == 0``, ``scale`` for vectors,
+    ``N(0, scale/√fan_in)`` otherwise.  Nothing passes through host memory,
+    so full-width configs materialize on one chip too."""
     leaves, treedef = jax.tree.flatten(tree, is_leaf=is_param)
-    rng = np.random.RandomState(seed)
-    out = []
-    for p in leaves:
-        if p.scale == 0.0:
-            arr = np.zeros(p.shape, dtype=np.float32)
-        elif len(p.shape) <= 1:
-            arr = np.ones(p.shape, dtype=np.float32) * p.scale
-        else:
-            fan_in = p.shape[-2] if len(p.shape) >= 2 else p.shape[-1]
-            arr = rng.normal(0, p.scale / math.sqrt(max(fan_in, 1)),
-                             p.shape).astype(np.float32)
-        out.append(jnp.asarray(arr, dtype=jnp.dtype(p.dtype)))
+    keys = jax.random.split(jax.random.PRNGKey(seed), max(len(leaves), 1))
+    out = [_init_leaf(k, tuple(p.shape), p.dtype, float(p.scale))
+           for p, k in zip(leaves, keys)]
     return jax.tree.unflatten(treedef, out)
 
 

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat  # noqa: F401  (optimization_barrier vmap rule on old JAX)
 from repro.core.events import CommEvent, decode_relative_perm
 from repro.core import tracer as _tracer
 
@@ -96,8 +95,8 @@ class LocalSim:
     Batched rank axis: the sequence point is shape-agnostic, so the same
     backend serves the per-rank path and the ``vmap``-ed signature-group
     path of :meth:`repro.core.replay.ProxyProgram.run_all`, where every
-    pool buffer carries a leading rank dimension (the required vmap rule
-    for ``optimization_barrier`` is registered by :mod:`repro.compat`).
+    pool buffer carries a leading rank dimension (JAX batches
+    ``optimization_barrier`` as the identity on batch dims).
 
     ``trace_events`` counts ``do`` calls *at trace time* (one per comm call
     site per program trace — loop bodies count once, like the grammar's

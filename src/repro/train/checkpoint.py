@@ -26,11 +26,10 @@ from typing import Any
 import jax
 import numpy as np
 
-from repro.compat import tree_flatten_with_path
 
 
 def _flatten_with_paths(tree) -> list[tuple[str, Any]]:
-    flat, _ = tree_flatten_with_path(tree)
+    flat, _ = jax.tree.flatten_with_path(tree)
     out = []
     for path, leaf in flat:
         key = "/".join(_path_str(p) for p in path)
@@ -147,7 +146,7 @@ class CheckpointManager:
                 continue
             arr = np.load(d / meta["file"])
             arrays[key] = arr
-        flat, treedef = tree_flatten_with_path(template)
+        flat, treedef = jax.tree.flatten_with_path(template)
         out_leaves = []
         shard_flat = (jax.tree.leaves(shardings) if shardings is not None
                       else [None] * len(flat))

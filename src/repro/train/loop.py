@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.configs.base import ArchConfig
 from repro.configs.registry import rules_for
 from repro.models.model import build_forward, init_params, logical_axes_tree

@@ -20,7 +20,8 @@ def test_device_comm_all_kinds_execute():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.compat import make_mesh
         from repro.sharding.collectives import DeviceComm
         from repro.launch.hlo_cost import analyze
 
@@ -105,7 +106,8 @@ def test_proxy_replay_on_mesh_runs():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.compat import make_mesh
         from repro.core.synthesize import synthesize
         from repro.core.replay import init_replay_state
         from repro.sharding.collectives import DeviceComm

@@ -24,7 +24,8 @@ def test_flash_kernel_sweep(b, s, h, g, d, win, causal, dtype, rng):
     q = jnp.asarray(rng.normal(0, 1, (b, s, h, d)), dtype)
     k = jnp.asarray(rng.normal(0, 1, (b, s, g, d)), dtype)
     v = jnp.asarray(rng.normal(0, 1, (b, s, g, d)), dtype)
-    out = flash_attention_fwd(q, k, v, causal=causal, window=win)
+    out = flash_attention_fwd(q, k, v, causal=causal, window=win,
+                              interpret=True)
     r = h // g
     qq = q.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     kk = jnp.repeat(k, r, axis=2).transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -49,7 +50,7 @@ def test_ssd_kernel_sweep(b, c, q, g, r, p, n, rng):
     cum = jnp.cumsum(adt, axis=2)
     bm = jnp.asarray(rng.normal(0, 1, (b, c, q, g, n)), jnp.float32)
     cm = jnp.asarray(rng.normal(0, 1, (b, c, q, g, n)), jnp.float32)
-    out = ssd_diag_block(x, dt, cum, bm, cm, r)
+    out = ssd_diag_block(x, dt, cum, bm, cm, r, interpret=True)
     ref = ssd_diag_ref(x.reshape(b, c, q, g, r, p), dt.reshape(b, c, q, g, r),
                        cum.reshape(b, c, q, g, r), bm, cm)
     np.testing.assert_allclose(np.asarray(out),
@@ -101,7 +102,7 @@ def test_ssd_prefill_state_matches_decode(rng):
 def test_mxu_block_kernel(reps, rng):
     a = jnp.asarray(rng.uniform(-1, 1, (128, 128)), jnp.bfloat16)
     b = jnp.asarray(rng.uniform(-1, 1, (128, 128)) / 128, jnp.bfloat16)
-    out = mxu_block(a, b, reps)
+    out = mxu_block(a, b, reps, interpret=True)
     ref = mxu_ref(a, b, reps)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=2e-2)
@@ -110,6 +111,6 @@ def test_mxu_block_kernel(reps, rng):
 @pytest.mark.parametrize("n,reps", [(2048, 3), (4096, 17)])
 def test_stream_block_kernel(n, reps, rng):
     v = jnp.asarray(rng.uniform(0, 1, (n,)), jnp.float32)
-    out = stream_block(v, reps)
+    out = stream_block(v, reps, interpret=True)
     ref = stream_ref(v, reps)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)

@@ -171,8 +171,8 @@ def test_run_all_group_results_isolated_across_ranks():
 
 
 def test_localsim_accepts_batched_rank_axis():
-    """LocalSim.do is vmappable over a leading rank axis (the compat layer
-    supplies the optimization_barrier batching rule on old JAX)."""
+    """LocalSim.do is vmappable over a leading rank axis (JAX batches
+    its ``optimization_barrier`` as the identity on batch dims)."""
     comm = LocalSim()
     st = {"buf0": jnp.full((4, 16), 0.5)}
 

@@ -239,7 +239,8 @@ def test_device_comm_batched_rank_axis_all_kinds():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import make_mesh, shard_map
+        from jax import shard_map
+        from repro.compat import make_mesh
         from repro.sharding.collectives import DeviceComm
 
         mesh = make_mesh((8,), ("x",))
@@ -362,6 +363,37 @@ def test_mesh_sharded_sweep_end_to_end():
                                       mesh=mesh)
         assert np.array_equal(fid_mesh.delta, fid_seq.delta)
         assert fid_mesh.mesh_checked
+        print("OK")
+    """))
+    assert "OK" in out
+
+
+def test_mesh_comm_events_match_each_rank_trace():
+    """The collectives of the executables the mesh sweep dispatches, read
+    back by the walker, are rank by rank the original trace's — also when
+    ranks diverge into two signature groups on disjoint devices."""
+    out = _run(textwrap.dedent("""\
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        from repro.core.events import CommEvent, ComputeEvent, is_comm
+        from repro.core.synthesize import synthesize
+        from repro.launch.mesh import make_replay_mesh
+
+        N = 4
+        comm = CommEvent("psum", (16,), "float32", ("x",))
+        perm = CommEvent("ppermute", (4, 4), "bfloat16", ("x",), ("shift", 1))
+        comp = ComputeEvent((2.1e6, 3.3e4, 1.1e6, 8.2e2, 0., 0.))
+        traces = [[comp, comm, comp, perm] * 5 + ([comm] if r == 0 else [])
+                  for r in range(N)]
+        res = synthesize(rank_traces=traces, axis_sizes={"x": N})
+        mesh = make_replay_mesh({"x": N})
+        assert len(res.proxy.mesh_sweep_plan(mesh)) == 2
+        sig = lambda evs: [(e.kind, tuple(e.shape), str(e.dtype),
+                            tuple(e.axes)) for e in evs]
+        got = res.proxy.mesh_comm_events(mesh)
+        assert sorted(got) == list(range(N))
+        for r, tr in enumerate(res.rank_traces):
+            assert sig(got[r]) == sig(e for e in tr if is_comm(e)), r
         print("OK")
     """))
     assert "OK" in out

@@ -17,7 +17,8 @@ _PROG = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.compat import make_mesh
     from repro.core.synthesize import synthesize
 
     mesh = make_mesh((8,), ("x",))

@@ -51,7 +51,8 @@ def test_gather_metric():
 
 
 def _shard_map_prog():
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.compat import make_mesh
     mesh = make_mesh((jax.device_count(),), ("x",))
     n = jax.device_count()
     from jax.sharding import PartitionSpec as P
@@ -82,7 +83,8 @@ def test_per_rank_traces_shift_dedup():
 
 
 def test_scan_with_collectives_unrolls_events():
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.compat import make_mesh
     mesh = make_mesh((jax.device_count(),), ("x",))
     from jax.sharding import PartitionSpec as P
 
@@ -97,6 +99,39 @@ def test_scan_with_collectives_unrolls_events():
     assert len(tr.comm_events()) == 7
 
 
+def test_nested_jit_inside_shard_map_keeps_collectives_and_flops():
+    """A nested ``jax.jit`` is a ``jit`` equation: the walker must enter it,
+    so its collectives become events and its matmul is MXU work."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    from repro.compat import make_mesh
+    n = jax.device_count()
+    mesh = make_mesh((n,), ("x",))
+
+    @jax.jit
+    def inner(u, w):
+        h = u @ w
+        return h + jax.lax.psum(h.sum(), "x")
+
+    def f(u, w):
+        h = inner(u, w)
+        return jax.lax.ppermute(h, "x", [(i, (i + 1) % n) for i in range(n)])
+
+    g = shard_map(f, mesh=mesh, in_specs=(P("x"), P()), out_specs=P("x"))
+    tr = trace_fn(g, jnp.ones((16 * n, 32)), jnp.ones((32, 32)))
+    assert [e.kind for e in tr.comm_events()] == ["psum", "ppermute"]
+    assert tr.total_compute()[0] == 2 * 16 * 32 * 32     # mxu flops
+
+
+def test_unknown_sub_jaxpr_primitive_raises():
+    """A primitive carrying a sub-jaxpr the walker does not know (here a
+    Pallas kernel) raises instead of being costed as one elementwise op."""
+    from repro.kernels.proxy_blocks.ops import mxu_block
+    a = jnp.ones((128, 128), jnp.float32)
+    with pytest.raises(NotImplementedError, match="pallas_call"):
+        trace_fn(lambda x, y: mxu_block(x, y, 1, interpret=True), a, a)
+
+
 def test_trace_session_interposition():
     with TraceSession(n_ranks=4) as sess:
         record_event(CommEvent("psum", (4,), "float32", ("x",)))
@@ -108,7 +143,8 @@ def test_trace_session_interposition():
 
 
 def test_instrumented_wrappers_record():
-    from repro.compat import make_mesh, shard_map
+    from jax import shard_map
+    from repro.compat import make_mesh
     from repro.sharding import collectives as C
     mesh = make_mesh((jax.device_count(),), ("x",))
     from jax.sharding import PartitionSpec as P
