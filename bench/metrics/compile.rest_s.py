@@ -1,0 +1,8 @@
+"""Proxy compile: seconds a program that the proxy's first ``run_all()``
+spent outside JAX's stages (state init, dispatch, first execution and the
+wait), in the timed window (program spans, ``bench/program_spans.py``)."""
+from bench import program_spans
+
+
+def read(rec):
+    return program_spans.read_part(rec, "rest")

@@ -27,7 +27,7 @@ Rows (→ ``artifacts/BENCH_9.json``):
    repeated nearest-scenario queries (index match + embedding distance +
    cached module/profile) timed per query, with the per-stage
    ``match/featurize/distance/profile`` latency split from the service's
-   :class:`~repro.serve.engine.StageTimers`.  Counters hard-assert the
+   :class:`~repro.obs.StageTimers`.  Counters hard-assert the
    hot path never re-enters synthesis.
 
 4. **batched_query_throughput** — N single :meth:`ProxyService.query`

@@ -19,6 +19,11 @@ L1_DCM, BR_CN/MSP).  Our 11 JAX blocks each excite ~1 TPU metric axis:
   11  loop_turn     scan_steps (the       block11 loop achieving linear
                     combo-loop overhead)  combination of other blocks
 
+Each block's loop runs in ``jax.named_scope("block.<name>")`` (the
+combo-loop padding in ``block.loop_turn``, block10 in ``block.empty_loop``),
+so the block's name reaches its ops' metadata and a profile can sum device
+time by block; a scope adds no jaxpr equation.
+
 Replay structure (faithful to the paper's "blocks 1-9 live inside block-11's
 loop, x11 >= sum(x_1..9)"): each block i runs in its own ``fori_loop`` of
 ``x_i`` turns, followed by one padding loop of ``x11 - sum(x_i)`` empty turns.
@@ -211,12 +216,15 @@ def run_combo(st: dict, x, unroll: int = 1) -> dict:
         raise ValueError(f"x11={x[10]} < sum(x1..9)={body}")
     for i, name in enumerate(BLOCK_NAMES[:9]):
         if x[i] > 0:
-            st = repeat_block(name, x[i], st, unroll)
+            with jax.named_scope(f"block.{name}"):
+                st = repeat_block(name, x[i], st, unroll)
     pad = x[10] - body
     if pad > 0:
-        st = empty_turns(pad, st)
+        with jax.named_scope("block.loop_turn"):
+            st = empty_turns(pad, st)
     if x[9] > 0:
-        st = empty_turns(x[9], st)
+        with jax.named_scope("block.empty_loop"):
+            st = empty_turns(x[9], st)
     return st
 
 
@@ -224,10 +232,13 @@ def run_combo_dyn(st: dict, x, unroll: int = 1) -> dict:
     """Traced-count variant (x: int32[11]); used by the jit replay engine."""
     x = jnp.asarray(x, jnp.int32)
     for i, name in enumerate(BLOCK_NAMES[:9]):
-        st = repeat_block(name, x[i], st, unroll)
+        with jax.named_scope(f"block.{name}"):
+            st = repeat_block(name, x[i], st, unroll)
     pad = jnp.maximum(x[10] - jnp.sum(x[:9]), 0)
-    st = empty_turns(pad, st)
-    st = empty_turns(x[9], st)
+    with jax.named_scope("block.loop_turn"):
+        st = empty_turns(pad, st)
+    with jax.named_scope("block.empty_loop"):
+        st = empty_turns(x[9], st)
     return st
 
 

@@ -51,7 +51,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec
 
-from repro import compat
+from repro import compat, obs
 from repro.core import blocks
 from repro.core import noise as noise_mod
 from repro.core import proxy_search
@@ -306,6 +306,9 @@ class ProxyProgram:
         self._shapes_key_cache = None      # filled by _shapes_key()
         self._counters = {"jit_traces": 0, "metric_traces": 0,
                           "batch_cache_hits": 0, "batch_cache_misses": 0}
+        #: root span id of the ``synthesize.program`` span that made this
+        #: proxy (0: none), the root of its ``proxy.run_all`` spans
+        self.root = 0
 
     # -- signature grouping ----------------------------------------------------
 
@@ -646,6 +649,11 @@ class ProxyProgram:
         and the :data:`~repro.core.noise.NOISE_COMPUTE` /
         :data:`~repro.core.noise.NOISE_COMM` leaves hold the perturbed
         cost accumulators :meth:`fidelity` summarizes.
+
+        Each call is the span ``proxy.run_all`` (:mod:`repro.obs`), under
+        the root id of the synthesis that made the proxy (:attr:`root`); a
+        first call's JAX trace, lowering and compile land in it as
+        ``jax.trace``, ``jax.lower`` and ``jax.compile``.
         """
         if ranks is not None:
             self._validate_ranks(ranks)
@@ -655,42 +663,47 @@ class ProxyProgram:
         if noise is not None and not batched:
             raise ValueError("noise= requires the batched replay path "
                              "(replicas ride the vmapped group axis)")
-        if mesh is not None:
-            return self._run_all_mesh(ranks, seed, batched, per_rank_seeds,
-                                      mesh, noise)
-        comm = comm or LocalSim()
-        if noise is not None:
+        with obs.span("proxy.run_all", root=self.root or None):
+            if mesh is not None:
+                return self._run_all_mesh(ranks, seed, batched, per_rank_seeds,
+                                          mesh, noise)
+            comm = comm or LocalSim()
+            if noise is not None:
+                out = {}
+                for fn, arg, grp in self._group_work(ranks, seed, comm,
+                                                     False, noise=noise):
+                    res = fn(arg)
+                    for r in grp:   # replicas are group-level, shared by ranks
+                        out[r] = dict(res)
+                for v in out.values():
+                    jax.block_until_ready(v)
+                return out
             out = {}
+            if not batched:
+                st = (None if per_rank_seeds
+                      else init_replay_state(self.module, seed))
+                for r in (range(self.merged.n_ranks) if ranks is None
+                          else ranks):
+                    out[r] = self._fn_for_rank(r, comm)(
+                        init_replay_state(self.module, seed + r)
+                        if per_rank_seeds else st)
+                for v in out.values():
+                    jax.block_until_ready(v)
+                return out
             for fn, arg, grp in self._group_work(ranks, seed, comm,
-                                                 False, noise=noise):
+                                                 per_rank_seeds):
                 res = fn(arg)
-                for r in grp:   # replicas are group-level, shared by ranks
-                    out[r] = dict(res)
+                if per_rank_seeds:
+                    for i, r in enumerate(grp):
+                        out[r] = jax.tree.map(lambda a, i=i: a[i], res)
+                else:
+                    # identical input + program -> identical output: a fresh
+                    # dict per rank; leaves alias on purpose (immutable)
+                    for r in grp:
+                        out[r] = dict(res)
             for v in out.values():
                 jax.block_until_ready(v)
             return out
-        out = {}
-        if not batched:
-            st = None if per_rank_seeds else init_replay_state(self.module, seed)
-            for r in (range(self.merged.n_ranks) if ranks is None else ranks):
-                out[r] = self._fn_for_rank(r, comm)(
-                    init_replay_state(self.module, seed + r)
-                    if per_rank_seeds else st)
-            for v in out.values():
-                jax.block_until_ready(v)
-            return out
-        for fn, arg, grp in self._group_work(ranks, seed, comm, per_rank_seeds):
-            res = fn(arg)
-            if per_rank_seeds:
-                for i, r in enumerate(grp):
-                    out[r] = jax.tree.map(lambda a, i=i: a[i], res)
-            else:
-                for r in grp:   # identical input + program -> identical output
-                    # fresh dict per rank; leaves alias on purpose (immutable)
-                    out[r] = dict(res)
-        for v in out.values():
-            jax.block_until_ready(v)
-        return out
 
     def _run_all_mesh(self, ranks, seed: int, batched: bool,
                       per_rank_seeds: bool, mesh,

@@ -32,6 +32,7 @@ from typing import Callable, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core import noise as noise_mod
 from repro.core import proxy_search
 from repro.core.events import Event, cluster_corpus, is_comm
@@ -157,9 +158,11 @@ def _assemble_result(store: TraceStore, grammars, merged, rank_ids, fits,
                          "(expected 'table' or 'unrolled')")
     noise_models = (noise_model.terminal_params(merged.table.events)
                     if noise_model is not None else None)
-    source = emit(merged, combos, name, axis_sizes,
-                  count_scale=count_scale, noise_models=noise_models)
-    module = load_module(source, name=f"{name}_mod", out_dir=out_dir)
+    with obs.span("synthesize.codegen"):
+        source = emit(merged, combos, name, axis_sizes,
+                      count_scale=count_scale, noise_models=noise_models)
+    with obs.span("synthesize.load"):
+        module = load_module(source, name=f"{name}_mod", out_dir=out_dir)
     proxy = ProxyProgram(source, module, merged, combos, axis_sizes)
 
     trace_bytes = store.raw_trace_bytes()
@@ -216,26 +219,46 @@ def synthesize(fn: Callable | None = None, *args,
     ``codegen="table"`` (default) emits the grammar-compiled program-table
     module; ``"unrolled"`` emits the per-symbol reference oracle — both
     replay the same program with bit-identical δ̄ and comm sequences.
-    """
-    if store is None:
-        if rank_traces is not None:
-            store = TraceStore.from_rank_traces(rank_traces, axis_sizes)
-        elif fn is not None:
-            store = trace_fn_store(fn, *args, axis_sizes=axis_sizes)
-        else:
-            raise ValueError("need fn, rank_traces, or store")
-    axis_sizes = dict(store.axis_sizes if axis_sizes is None else axis_sizes)
 
-    grammars, merged, rank_ids, reps = compress_store(store, rel_tol,
-                                                      threshold)
-    fits, combos, solver = _fit_terminals(merged.table, reps, solver,
-                                          count_scale)
-    # same rel_tol → same cluster assignment as compress_store, so the
-    # calibrated σ keys line up with the merged table's cluster ids
-    noise_model = noise_mod.calibrate(store, rel_tol=rel_tol)
-    return _assemble_result(store, grammars, merged, rank_ids, fits, combos,
-                            solver, name, axis_sizes, count_scale, out_dir,
-                            codegen=codegen, noise_model=noise_model)
+    The call is the root span ``synthesize.program`` of one program (see
+    :mod:`repro.obs`), with the children ``synthesize.trace``,
+    ``compress`` (:func:`compress_store`), ``synthesize.fit``, ``.noise``,
+    ``.codegen`` and ``.load``; ``stats["stage_ms"]`` is its split by the
+    innermost span, ``rest`` included, and ``stats["counts"]`` the
+    counters under it.  The proxy keeps the root's id (``proxy.root``) for
+    the spans of its runs.
+    """
+    if store is None and rank_traces is None and fn is None:
+        raise ValueError("need fn, rank_traces, or store")
+    with obs.span("synthesize.program") as top:
+        if store is None:
+            with obs.span("synthesize.trace"):
+                if rank_traces is not None:
+                    store = TraceStore.from_rank_traces(rank_traces,
+                                                        axis_sizes)
+                else:
+                    store = trace_fn_store(fn, *args, axis_sizes=axis_sizes)
+        axis_sizes = dict(store.axis_sizes if axis_sizes is None
+                          else axis_sizes)
+
+        grammars, merged, rank_ids, reps = compress_store(store, rel_tol,
+                                                          threshold)
+        with obs.span("synthesize.fit"):
+            fits, combos, solver = _fit_terminals(merged.table, reps, solver,
+                                                  count_scale)
+        # same rel_tol → same cluster assignment as compress_store, so the
+        # calibrated σ keys line up with the merged table's cluster ids
+        with obs.span("synthesize.noise"):
+            noise_model = noise_mod.calibrate(store, rel_tol=rel_tol)
+        res = _assemble_result(store, grammars, merged, rank_ids, fits,
+                               combos, solver, name, axis_sizes, count_scale,
+                               out_dir, codegen=codegen,
+                               noise_model=noise_model)
+    under = obs.descendants(top)
+    res.stats["stage_ms"] = obs.stage_ms(top, under)
+    res.stats["counts"] = obs.counts_of([top, *under])
+    res.proxy.root = top.root
+    return res
 
 
 # ---------------------------------------------------------------------------
@@ -507,33 +530,36 @@ def _synthesize_corpus_incremental(cstore, threshold: float,
     id_of: dict[str, tuple] = {}
     mergeds: list[MergedProgram] = []
     n_front_reused = 0
-    front_profile: dict = {}
     g_hits0 = cstore.grammars.hits
     g_miss0 = cstore.grammars.misses
-    for sname in names:
-        cids = ids_by_name[sname]
-        ident = (cstore.content_hash(sname),
-                 hashlib.sha256(cids.tobytes()).hexdigest(), threshold)
-        id_of[sname] = ident
-        key = ("front",) + ident
-        hit = cstore.memo.get(key)
-        if hit is None:
-            # scenarios without an in-memory front-half memo (new content,
-            # or a freshly opened store) still skip Sequitur for every
-            # rank stream already in the persisted grammar cache
-            grammars, merged, rank_ids, _ = compress_store(
-                cstore.load_scenario(sname), cstore.rel_tol, threshold,
-                cluster_ids=cids, reps=reps,
-                grammar_cache=cstore.grammars, profile=front_profile)
-            hit = (grammars, merged, rank_ids)
-            cstore.memo[key] = hit
-        else:
-            n_front_reused += 1
-        grammars, merged, rank_ids = hit
-        # fresh per-rank id-list copies: memoized grammars/merged are
-        # read-only downstream, but id lists are caller-mutable
-        per[sname] = (grammars, merged, [list(ids) for ids in rank_ids])
-        mergeds.append(merged)
+    with obs.span("corpus.front") as front:
+        for sname in names:
+            cids = ids_by_name[sname]
+            ident = (cstore.content_hash(sname),
+                     hashlib.sha256(cids.tobytes()).hexdigest(), threshold)
+            id_of[sname] = ident
+            key = ("front",) + ident
+            hit = cstore.memo.get(key)
+            if hit is None:
+                # scenarios without an in-memory front-half memo (new
+                # content, or a freshly opened store) still skip Sequitur
+                # for every rank stream already in the persisted grammar
+                # cache
+                grammars, merged, rank_ids, _ = compress_store(
+                    cstore.load_scenario(sname), cstore.rel_tol, threshold,
+                    cluster_ids=cids, reps=reps,
+                    grammar_cache=cstore.grammars)
+                hit = (grammars, merged, rank_ids)
+                cstore.memo[key] = hit
+            else:
+                n_front_reused += 1
+            grammars, merged, rank_ids = hit
+            # fresh per-rank id-list copies: memoized grammars/merged are
+            # read-only downstream, but id lists are caller-mutable
+            per[sname] = (grammars, merged, [list(ids) for ids in rank_ids])
+            mergeds.append(merged)
+    grammar_ms = sum(s.ms for s in obs.descendants(front)
+                     if s.name == "compress.grammar")
     cstore.save_grammars()
 
     table, gid_maps = corpus_terminal_table(mergeds)
@@ -598,7 +624,7 @@ def _synthesize_corpus_incremental(cstore, threshold: float,
         n_solver_calls=1 if miss_targets else 0,
         n_grammar_cache_hits=cstore.grammars.hits - g_hits0,
         n_grammar_cache_misses=cstore.grammars.misses - g_miss0,
-        grammar_ms=round(front_profile.get("grammar_ms", 0.0), 3),
+        grammar_ms=round(grammar_ms, 3),
     )
     return CorpusResult(results=results, table=table, reps=reps, stats=stats,
                         fits=corpus_fits)

@@ -44,6 +44,7 @@ from typing import Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.events import (
     CommEvent, ComputeEvent, Event, N_METRICS, cluster_vectors,
     encode_relative_perm, is_comm,
@@ -470,82 +471,89 @@ def compress_store(store: TraceStore,
     alias across hits, read-only downstream like the per-class grammar
     aliasing below.
 
-    ``profile`` (a dict) accumulates per-stage wall-clock and cache
-    counters: ``cluster_ms``/``intern_ms``/``grammar_ms``/``merge_ms``,
-    ``n_distinct_streams``/``n_sequitur_runs``, and
-    ``grammar_cache_hits``/``grammar_cache_misses``.  Keys add onto
-    existing values so one dict can aggregate across scenarios.
+    The call is the span ``compress`` (:mod:`repro.obs`), with a child
+    span for each stage (``compress.cluster``, one ``compress.intern``
+    and one ``compress.grammar`` per distinct stream, ``compress.merge``)
+    and the stream and cache counts as its counters (``compress.streams``,
+    ``compress.sequitur_runs``, ``compress.grammar_cache_hits``/
+    ``_misses``).  ``profile`` (a dict) accumulates them, the times read
+    from those spans (0 while recording is off): ``cluster_ms``/
+    ``intern_ms``/``grammar_ms``/``merge_ms``, ``n_distinct_streams``/
+    ``n_sequitur_runs``, and ``grammar_cache_hits``/
+    ``grammar_cache_misses``.  Keys add onto existing values so one dict
+    can aggregate across scenarios.
     """
-    from time import perf_counter
-
-    t0 = perf_counter()
-    if cluster_ids is None:
-        cluster_ids, reps = cluster_vectors(store.metrics, rel_tol)
-    else:
-        cluster_ids = np.asarray(cluster_ids, dtype=np.int64)
-        if reps is None:
-            raise ValueError("cluster_ids without reps")
-    t_cluster = perf_counter() - t0
-
-    n_comms = len(store.comm_pool)
-    toks = store.tokens
-    sym_all = rank_symbol_streams(store, cluster_ids)
-
-    grammars: list[Grammar] = []
-    rank_ids: list[list[int]] = []
-    cache: dict[bytes, tuple[Grammar, list[int]]] = {}
-    t_intern = t_grammar = 0.0
-    n_runs = n_hits = n_misses = 0
-    for r in range(store.n_ranks):
-        sl = slice(int(store.extents[r]), int(store.extents[r + 1]))
-        sym = sym_all[sl]
-        key = sym.tobytes()
-        hit = cache.get(key)
-        if hit is None:
-            t1 = perf_counter()
-            local_ids, uniq, first = _first_appearance_factorize(sym)
-            table = TerminalTable()
-            rtoks = toks[sl]
-            for s, fi in zip(uniq.tolist(), first.tolist()):
-                if s < n_comms:
-                    table.intern(store.comm_pool[s])
-                else:
-                    row = int(rtoks[fi])
-                    table.intern(ComputeEvent(
-                        tuple(store.metrics[row].tolist()),
-                        cluster_id=int(s - n_comms)))
-            t2 = perf_counter()
-            t_intern += t2 - t1
-            rules = gkey = None
-            if grammar_cache is not None:
-                gkey = grammar_cache.key(local_ids, threshold)
-                rules = grammar_cache.get(gkey)
-            if rules is None:
-                if gkey is not None:
-                    n_misses += 1
-                seq = Sequitur()
-                seq.push_runs(*rle_runs(local_ids))
-                rules = seq.grammar_rules()
-                n_runs += 1
-                if gkey is not None:
-                    grammar_cache.put(gkey, rules)
+    with obs.span("compress") as top:
+        with obs.span("compress.cluster"):
+            if cluster_ids is None:
+                cluster_ids, reps = cluster_vectors(store.metrics, rel_tol)
             else:
-                n_hits += 1
-            t_grammar += perf_counter() - t2
-            hit = (Grammar(rules=rules, table=table), local_ids.tolist())
-            cache[key] = hit
-        grammars.append(hit[0])
-        # grammars deliberately alias across a signature class (read-only
-        # downstream, tested); id lists get a per-rank copy so in-place
-        # edits by callers can't corrupt sibling ranks
-        rank_ids.append(list(hit[1]))
-    t3 = perf_counter()
-    merged = merge_grammars(grammars, threshold)
+                cluster_ids = np.asarray(cluster_ids, dtype=np.int64)
+                if reps is None:
+                    raise ValueError("cluster_ids without reps")
+
+        n_comms = len(store.comm_pool)
+        toks = store.tokens
+        sym_all = rank_symbol_streams(store, cluster_ids)
+
+        grammars: list[Grammar] = []
+        rank_ids: list[list[int]] = []
+        cache: dict[bytes, tuple[Grammar, list[int]]] = {}
+        n_runs = n_hits = n_misses = 0
+        for r in range(store.n_ranks):
+            sl = slice(int(store.extents[r]), int(store.extents[r + 1]))
+            sym = sym_all[sl]
+            key = sym.tobytes()
+            hit = cache.get(key)
+            if hit is None:
+                with obs.span("compress.intern"):
+                    local_ids, uniq, first = _first_appearance_factorize(sym)
+                    table = TerminalTable()
+                    rtoks = toks[sl]
+                    for s, fi in zip(uniq.tolist(), first.tolist()):
+                        if s < n_comms:
+                            table.intern(store.comm_pool[s])
+                        else:
+                            row = int(rtoks[fi])
+                            table.intern(ComputeEvent(
+                                tuple(store.metrics[row].tolist()),
+                                cluster_id=int(s - n_comms)))
+                with obs.span("compress.grammar"):
+                    rules = gkey = None
+                    if grammar_cache is not None:
+                        gkey = grammar_cache.key(local_ids, threshold)
+                        rules = grammar_cache.get(gkey)
+                    if rules is None:
+                        if gkey is not None:
+                            n_misses += 1
+                        seq = Sequitur()
+                        seq.push_runs(*rle_runs(local_ids))
+                        rules = seq.grammar_rules()
+                        n_runs += 1
+                        if gkey is not None:
+                            grammar_cache.put(gkey, rules)
+                    else:
+                        n_hits += 1
+                hit = (Grammar(rules=rules, table=table), local_ids.tolist())
+                cache[key] = hit
+            grammars.append(hit[0])
+            # grammars deliberately alias across a signature class (read-only
+            # downstream, tested); id lists get a per-rank copy so in-place
+            # edits by callers can't corrupt sibling ranks
+            rank_ids.append(list(hit[1]))
+        with obs.span("compress.merge"):
+            merged = merge_grammars(grammars, threshold)
+        for k, v in (("compress.streams", len(cache)),
+                     ("compress.sequitur_runs", n_runs),
+                     ("compress.grammar_cache_hits", n_hits),
+                     ("compress.grammar_cache_misses", n_misses)):
+            obs.count(k, v)
     if profile is not None:
-        for k, v in (("cluster_ms", t_cluster * 1e3),
-                     ("intern_ms", t_intern * 1e3),
-                     ("grammar_ms", t_grammar * 1e3),
-                     ("merge_ms", (perf_counter() - t3) * 1e3),
+        ns: dict[str, int] = {}
+        for sp in obs.descendants(top):
+            ns[sp.name] = ns.get(sp.name, 0) + sp.ns
+        for k, v in (*((f"{st}_ms", ns.get(f"compress.{st}", 0) * 1e-6)
+                       for st in ("cluster", "intern", "grammar", "merge")),
                      ("n_distinct_streams", len(cache)),
                      ("n_sequitur_runs", n_runs),
                      ("grammar_cache_hits", n_hits),

@@ -8,9 +8,7 @@ context × 128-slot pool fit per chip.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
@@ -18,29 +16,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.models.model import build_forward, init_cache
-
-
-class StageTimers:
-    """Per-stage wall-clock accumulators for serving observability —
-    shared by both serving tiers (:class:`ServeEngine` prefill/decode,
-    :class:`~repro.serve.proxy_service.ProxyService`
-    match/featurize/distance/profile).  ``time(stage)`` is a context
-    manager; :meth:`snapshot_ms` renders ``{stage}_ms`` keys for a stats
-    dict or a benchmark row."""
-
-    def __init__(self, *stages: str):
-        self._acc = {s: 0.0 for s in stages}
-
-    @contextlib.contextmanager
-    def time(self, stage: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self._acc[stage] += time.perf_counter() - t0
-
-    def snapshot_ms(self) -> dict[str, float]:
-        return {f"{s}_ms": round(v * 1e3, 3) for s, v in self._acc.items()}
+from repro.obs import StageTimers
 
 
 @dataclasses.dataclass
@@ -59,7 +35,7 @@ class ServeEngine:
         self.mesh = mesh
         self.max_len = max_len
         self.eos_id = eos_id
-        self.timers = StageTimers("prefill", "decode")
+        self.timers = StageTimers("serve", "prefill", "decode")
         self._prefill = jax.jit(
             lambda p, b: build_forward(cfg, "prefill")(p, b, cfg, mesh))
         self._decode = jax.jit(
@@ -84,39 +60,38 @@ class ServeEngine:
         assert plen + n_new <= self.max_len, "exceeds engine max_len"
         batch = {"tokens": jnp.asarray(prompts, jnp.int32), **self._extras(b)}
 
-        t0 = time.perf_counter()
-        logits, pre_cache = self._prefill(self.params, batch)
-        jax.block_until_ready(logits)
-        t1 = time.perf_counter()
+        with self.timers.time("prefill") as pre:
+            logits, pre_cache = self._prefill(self.params, batch)
+            jax.block_until_ready(logits)
 
-        # re-home the prefill cache into full-length decode buffers
-        full = init_cache(self.cfg, b, self.max_len,
-                          self.cfg.n_audio_frames or 0)
-        cache = jax.tree.map(self._embed_cache, full, pre_cache)
+        with self.timers.time("decode") as dec:
+            # re-home the prefill cache into full-length decode buffers
+            full = init_cache(self.cfg, b, self.max_len,
+                              self.cfg.n_audio_frames or 0)
+            cache = jax.tree.map(self._embed_cache, full, pre_cache)
 
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        out = [np.asarray(tok)]
-        done = np.zeros((b,), bool)
-        for i in range(n_new - 1):
-            dbatch = {"tokens": tok[:, None]}
-            logits, cache = self._decode(self.params, cache, dbatch,
-                                         jnp.int32(plen + i))
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            t_np = np.asarray(tok)
-            if self.eos_id >= 0:
-                done |= t_np == self.eos_id
-                t_np = np.where(done, self.eos_id, t_np)
-            out.append(t_np)
-            if done.all():
-                break
-        jax.block_until_ready(tok)
-        t2 = time.perf_counter()
-        self.timers._acc["prefill"] += t1 - t0
-        self.timers._acc["decode"] += t2 - t1
+            out = [np.asarray(tok)]
+            done = np.zeros((b,), bool)
+            for i in range(n_new - 1):
+                dbatch = {"tokens": tok[:, None]}
+                logits, cache = self._decode(self.params, cache, dbatch,
+                                             jnp.int32(plen + i))
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                t_np = np.asarray(tok)
+                if self.eos_id >= 0:
+                    done |= t_np == self.eos_id
+                    t_np = np.where(done, self.eos_id, t_np)
+                out.append(t_np)
+                if done.all():
+                    break
+            jax.block_until_ready(tok)
         gen = np.stack(out, axis=1)
         n_tok = gen.size
-        return GenResult(tokens=gen, prefill_sec=t1 - t0, decode_sec=t2 - t1,
-                         tokens_per_sec=n_tok / max(t2 - t1, 1e-9))
+        prefill_sec, decode_sec = pre.ns * 1e-9, dec.ns * 1e-9
+        return GenResult(tokens=gen, prefill_sec=prefill_sec,
+                         decode_sec=decode_sec,
+                         tokens_per_sec=n_tok / max(decode_sec, 1e-9))
 
     @staticmethod
     def _embed_cache(full_leaf, pre_leaf):

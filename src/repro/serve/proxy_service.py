@@ -48,7 +48,8 @@ corpus mutation:
 
 * **Observability.**  ``stats`` carries per-stage latency accumulators
   (``match_ms``/``featurize_ms``/``distance_ms``/``profile_ms``, via the
-  shared :class:`repro.serve.engine.StageTimers`) and hit-rate counters;
+  shared :class:`repro.obs.StageTimers`, a view of the
+  ``service.*`` spans) and hit-rate counters;
   ``benchmarks/corpus_scale.py`` snapshots them per row.
 
 No Sequitur, no fit dispatch, no codegen on the hot path — the ``stats``
@@ -80,7 +81,7 @@ from repro.core.trace_ir import (
     TraceStore, _first_appearance_factorize, rank_symbol_streams,
 )
 from repro.serve.ann import BallTree
-from repro.serve.engine import StageTimers
+from repro.obs import StageTimers
 
 _KIND_INDEX = {k: i for i, k in enumerate(COMM_KINDS)}
 _N_COEF = 11                       # block-combination loop counts (x_1..x_11)
@@ -171,7 +172,7 @@ class ProxyService:
         self._ann_threshold = int(ann_threshold)
         self._lock = threading.RLock()
         self._stale = False
-        self._timers = StageTimers("match", "featurize", "distance",
+        self._timers = StageTimers("service", "match", "featurize", "distance",
                                    "profile")
         self.stats = {
             "n_warm_synthesis": 0,

@@ -2,6 +2,8 @@
 
 Hypothesis-based property tests live in test_blocks_qp_prop.py so this
 module always runs, dependency or not."""
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -54,6 +56,22 @@ def test_run_combo_executes():
     out = B.run_combo(st_, [2, 1, 3, 1, 1, 1, 1, 1, 1, 4, 15])
     assert np.isfinite(np.asarray(out["a"], np.float32)).all()
     assert np.isfinite(float(out["s"]))
+
+
+@pytest.mark.parametrize("dyn", [False, True], ids=["static", "traced"])
+def test_block_scopes_reach_op_metadata(dyn):
+    """Each active block's ops carry ``block.<name>`` in their metadata, so
+    a profile can sum device time by block.  The padding loops are empty:
+    they lower to no op and carry nothing."""
+    x = (1,) * 9 + (2, 12)
+    st = B.init_state()
+    if dyn:
+        low = jax.jit(B.run_combo_dyn).lower(st, np.asarray(x, np.int32))
+    else:
+        low = jax.jit(lambda s: B.run_combo(s, x)).lower(st)
+    text = low.as_text(debug_info=True)
+    scopes = set(re.findall(r"block\.([a-z_]+)", text))
+    assert scopes == set(B.BLOCK_NAMES[:9])
 
 
 def test_fit_recovers_exact_combination():
