@@ -32,6 +32,20 @@ Total loop turns = x11.  Hence one application of block i physically costs
 paper's coupled QP (eq. 6-7 + x11 constraint) into a plain NNLS — see
 :mod:`repro.core.proxy_search`.
 
+``move_shift`` rotates the 128 KiB rows of a ``(rows, 32768)`` f32
+buffer, and one loop turn runs its ``unroll`` applications as one op over
+``unroll`` distinct rows: chained on one vector, XLA composes an even
+number of half-swaps into the identity and deletes the block.  The state
+holds one buffer per row count its program's ``move_shift`` turns use
+(:func:`row_count`), made on the device from the seed; an unroll past
+``_ROWS_MAX`` runs as passes over the whole buffer, each behind an
+optimization barrier so no two passes cancel.  Where a buffer lives is the
+compiler's choice: on a TPU v5e a loop-carried buffer of up to about
+96 MiB stays in VMEM.  The other blocks chain their applications on
+operands of a few KiB to 128 KiB, which XLA may fuse (``hbm_stream``'s
+chain is one pass) or share (``reduce_long``'s and ``gather_rand``'s
+reads of an unchanged operand).
+
 Calibration (the ``mini-proxy-app`` measurement producing matrix B, eq. 2)
 runs the *same* jaxpr cost walker used to trace target programs, so the fit
 is exactly self-consistent: the walker cost of generated proxy code equals
@@ -40,13 +54,14 @@ is exactly self-consistent: the walker cost of generated proxy code equals
 from __future__ import annotations
 
 import functools
-from typing import Callable
+from typing import Callable, Iterable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro import obs
 from repro.core.events import N_METRICS
 
 BLOCK_NAMES: tuple[str, ...] = (
@@ -56,23 +71,61 @@ BLOCK_NAMES: tuple[str, ...] = (
 )
 N_BLOCKS = len(BLOCK_NAMES)
 
-# geometry constants (sized so working sets are VMEM-resident on TPU and
-# replay on CPU stays fast; MXU dims are multiples of 128)
+# geometry constants (MXU dims are multiples of 128; operands of a few KiB
+# to 128 KiB, and the move_shift rows sized by the program's unroll)
 _MM = 128            # mxu_vmem tile
 _MS = 8              # mxu_small M-dim (low arithmetic intensity)
-_VEC = 1 << 15       # hbm_stream vector (128 KiB f32): small quanta limit
-                     # integer-rounding error even for few-MB events; unroll
-                     # absorbs the extra loop turns
+_VEC = 1 << 15       # hbm_stream vector and move_shift row (128 KiB f32):
+                     # small quanta limit integer-rounding error even for
+                     # few-MB events; unroll absorbs the extra loop turns
+_ROWS_MAX = 512      # rows of one move_shift buffer (64 MiB); a larger
+                     # unroll runs as passes over it
 _TILE = (32, 128)    # VPU tile
 _TAB = 1 << 14       # gather table
 _NIDX = 4096         # gather indices
 _SCAN_LEN = 64       # scan_seq inner length
 
 
-def init_state(seed: int = 0) -> dict:
-    """Fixed-shape pytree threaded through every block (DCE-proof carry)."""
+def row_count(unroll: int) -> int:
+    """Rows of the buffer a ``move_shift`` turn at ``unroll`` runs over: the
+    unroll, at most ``_ROWS_MAX``, of which a larger unroll must be a
+    multiple (the turn then makes ``unroll // _ROWS_MAX`` passes)."""
+    u = int(unroll)
+    if u > _ROWS_MAX and u % _ROWS_MAX:
+        raise ValueError(f"unroll {u} > {_ROWS_MAX} is not a multiple of it")
+    return min(u, _ROWS_MAX)
+
+
+def _row_key(rows: int) -> str:
+    """State key of the ``rows``-row buffer."""
+    return f"rows{rows}"
+
+
+def row_unrolls(combos: Iterable) -> tuple[int, ...]:
+    """The unrolls of the ``(x, unroll)`` combos that run ``move_shift``:
+    what :func:`init_state` sizes the row buffers from."""
+    i = BLOCK_NAMES.index("move_shift")
+    return tuple(sorted({int(u) for x, u in combos if int(x[i]) > 0}))
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _fill_rows(seed, rows: int):
+    """``rows`` rows of values in [0, 1), an integer hash of ``seed`` and
+    each element's index: quick to compile, where a PRNG's fill takes
+    seconds on a TPU."""
+    x = lax.iota(jnp.uint32, rows * _VEC) * jnp.uint32(0x9E3779B1) + seed
+    x = (x ^ (x >> 16)) * jnp.uint32(0x85EBCA6B)
+    x = (x ^ (x >> 13)) * jnp.uint32(0xC2B2AE35)
+    x = x ^ (x >> 16)
+    return ((x >> 8).astype(jnp.float32) * 2.0 ** -24).reshape(rows, _VEC)
+
+
+def init_state(seed: int = 0, unrolls: Iterable[int] = (1,)) -> dict:
+    """Fixed-shape pytree threaded through every block (DCE-proof carry),
+    with one ``move_shift`` buffer for each row count that turns at
+    ``unrolls`` run over, filled on the device from the seed."""
     rng = np.random.RandomState(seed)
-    return {
+    st = {
         "a": jnp.asarray(rng.uniform(-1, 1, (_MM, _MM)), jnp.bfloat16),
         # matmul operands carry the 1/128 contraction normalization baked in
         # so the MXU blocks emit *zero* VPU ops (pure-matmul targets must be
@@ -86,13 +139,18 @@ def init_state(seed: int = 0) -> dict:
         "idx": jnp.asarray(rng.randint(0, _TAB, (_NIDX,)), jnp.int32),
         "s": jnp.float32(0.0),
     }
+    draw = int(rng.randint(0, 2 ** 31))
+    for rows in sorted({row_count(u) for u in unrolls}):
+        st[_row_key(rows)] = _fill_rows(
+            np.uint32((draw + rows * 0x632BE5AB) % 2 ** 32), rows)
+    return st
 
 
 # -- the block bodies (one "application" each) --------------------------------
 
 
 def mxu_vmem(st: dict) -> dict:
-    """128x128x128 bf16 matmul, VMEM-resident: high-AI MXU pressure."""
+    """128x128x128 bf16 matmul on 32 KiB operands: high-AI MXU pressure."""
     st = dict(st)
     st["a"] = st["a"] @ st["b"]
     return st
@@ -161,19 +219,20 @@ def scan_seq(st: dict) -> dict:
     return st
 
 
-def move_shift(st: dict) -> dict:
-    """pure data movement (slice+concat roll): bytes with zero element ops.
+def move_shift(st: dict, k: str) -> dict:
+    """pure data movement (slice+concat roll of every row of buffer ``k``):
+    bytes with zero element ops.
 
     TPU has no branch predictor, so the paper's msp blocks have no analogue
     (DESIGN.md §2); the freed slot covers the pure-copy segments real traces
     contain (layout changes, halo packing) that no ALU block can represent."""
     st = dict(st)
-    v = st["v"]
-    st["v"] = jnp.concatenate([v[_VEC // 2:], v[:_VEC // 2]])
+    v = st[k]
+    st[k] = jnp.concatenate([v[:, _VEC // 2:], v[:, :_VEC // 2]], axis=1)
     return st
 
 
-BLOCK_FNS: dict[str, Callable[[dict], dict]] = {
+BLOCK_FNS: dict[str, Callable[..., dict]] = {
     "mxu_vmem": mxu_vmem, "mxu_small": mxu_small, "hbm_stream": hbm_stream,
     "vpu_chain": vpu_chain, "trans_chain": trans_chain,
     "gather_rand": gather_rand, "reduce_long": reduce_long,
@@ -185,13 +244,29 @@ def repeat_block(name: str, n, st: dict, unroll: int = 1) -> dict:
     """Run block ``name`` for ``n`` loop turns of ``unroll`` inlined
     applications each (the paper places x_i block *instances* inside the
     block-11 loop body; unroll is that instance count — it decouples the
-    application count from the loop-turn/serialization count)."""
-    fn = BLOCK_FNS[name]
+    application count from the loop-turn/serialization count).
 
-    def body(i, s):
-        for _ in range(unroll):
-            s = fn(s)
-        return s
+    A ``move_shift`` turn instead makes ``unroll // row_count(unroll)``
+    ops over the rows of its buffer, each behind an optimization barrier
+    (free for the walker), and adds ``unroll`` to the trace-time counter
+    ``blocks.byte_rows``."""
+    fn = BLOCK_FNS[name]
+    if name == "move_shift":
+        obs.count("blocks.byte_rows", int(unroll))
+        rows = row_count(unroll)
+        k = _row_key(rows)
+
+        def body(i, s):
+            for _ in range(unroll // rows):
+                s = dict(s)
+                s[k] = lax.optimization_barrier(s[k])
+                s = fn(s, k)
+            return s
+    else:
+        def body(i, s):
+            for _ in range(unroll):
+                s = fn(s)
+            return s
 
     return lax.fori_loop(0, n, body, st)
 
@@ -249,16 +324,20 @@ def run_combo_dyn(st: dict, x, unroll: int = 1) -> dict:
 def calibration_matrix() -> np.ndarray:
     """B[i, j]: metric i per single application of block j (walker-measured).
 
-    Columns 1-9 are the *bare* block bodies (the loop turn each application
-    carries at replay is column 11; proxy_search adds it via the constraint
-    substitution).  Columns 10 and 11 are one empty loop turn each.
+    Columns 1-9 are the *bare* block bodies, ``move_shift`` on one row (the
+    loop turn each application carries at replay is column 11; proxy_search
+    adds it via the constraint substitution).  Columns 10 and 11 are one
+    empty loop turn each.
     """
     from repro.core.tracer import compute_cost  # local import: cycle-free
 
     st = jax.eval_shape(init_state)
     b = np.zeros((N_METRICS, N_BLOCKS))
     for j, name in enumerate(BLOCK_NAMES[:9]):
-        b[:, j] = compute_cost(BLOCK_FNS[name], st)
+        fn = BLOCK_FNS[name]
+        if name == "move_shift":
+            fn = functools.partial(fn, k=_row_key(1))
+        b[:, j] = compute_cost(fn, st)
     # one loop turn: fori_loop(0, K, identity) / K  ->  scan_steps == 1
     k = 1024
     turn = compute_cost(lambda s: empty_turns(k, s), st) / k

@@ -34,6 +34,7 @@ from __future__ import annotations
 import textwrap
 from typing import Mapping, Sequence
 
+from repro.core.blocks import row_unrolls
 from repro.core.events import CommEvent, ComputeEvent, is_comm
 from repro.core.interproc import MergedProgram
 
@@ -155,6 +156,7 @@ def generate_source(merged: MergedProgram,
             w(f"    # t{gid}: MPI_Compute proxy, cluster {ev.cluster_id}")
             w(f"    ('compute', {tuple(int(v) for v in x)!r}, {int(unroll)}),")
     w(")")
+    w(_row_unrolls_line(combos))
     w("")
     w(_noise_models_block(merged, noise_models))
     w("")
@@ -243,6 +245,13 @@ def generate_source(merged: MergedProgram,
             return tuple(sig)
     """))
     return "\n".join(L)
+
+
+def _row_unrolls_line(combos: Mapping[int, tuple]) -> str:
+    """``ROW_UNROLLS``, shared by both codegen flavors: the unrolls of the
+    combos that run ``move_shift``, from which replay sizes its row
+    buffers (:func:`repro.core.blocks.init_state`)."""
+    return f"ROW_UNROLLS = {row_unrolls(combos.values())!r}"
 
 
 def _noise_models_block(merged: MergedProgram,

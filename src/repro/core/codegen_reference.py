@@ -28,7 +28,7 @@ from typing import Mapping
 
 from repro.core.codegen import (
     _comm_buffers, _fmt_rankset, _fmt_ranktuple, _main_runs,
-    _noise_models_block, _syms_comm_axes, _topo_order,
+    _noise_models_block, _row_unrolls_line, _syms_comm_axes, _topo_order,
     compute_signature_groups, group_device_hint,
 )
 from repro.core.events import is_comm
@@ -90,6 +90,7 @@ def generate_source(merged: MergedProgram,
               f"{int(unroll)}),  # t{gid}")
     w(")")
     w("_NZ = _noise.lower_params(NOISE_MODELS, _NOISE_DESCS)")
+    w(_row_unrolls_line(combos))
     w("")
 
     # -- terminals -------------------------------------------------------------

@@ -105,8 +105,9 @@ def load_module(source: str, name: str = "generated_proxy",
 
 
 def init_replay_state(module, seed: int = 0) -> dict:
-    """Block state + the generated module's comm buffer pool."""
-    st = blocks.init_state(seed)
+    """Block state, with the ``move_shift`` row buffers the module's
+    ``ROW_UNROLLS`` ask for, + the generated module's comm buffer pool."""
+    st = blocks.init_state(seed, module.ROW_UNROLLS)
     for bname, (shape, dtype) in module.COMM_BUFFERS.items():
         st[bname] = jnp.full(shape, 0.5, dtype=dtype)
     return st
