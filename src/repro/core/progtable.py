@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from repro.core import blocks
 from repro.core import noise as noise_mod
 from repro.core.replay import REP_UNROLL_THRESHOLD, rep
+from repro.sharding.collectives import runs
 
 #: Symbol sequences shorter than this stay straight-line: a switch-scan
 #: needs the opcode array + dispatch machinery, which only pays for itself
@@ -175,11 +176,16 @@ class ProgramTable:
 
         opcodes = np.asarray([index[(k, int(r), int(e))] for k, r, e in seq],
                              dtype=np.int32)
+        taken = np.bincount(opcodes, minlength=len(entries))
 
         def switched(st, comm, _entries=entries, _ops=opcodes):
+            def branch(s, fn, e, n):
+                with runs(n):       # traced once, taken n times a scan
+                    return rep(fn, e, s, comm)
+
             branches = [
-                (lambda s, _fn=fn, _e=e: rep(_fn, _e, s, comm))
-                for fn, e in _entries
+                (lambda s, _fn=fn, _e=e, _n=int(n): branch(s, _fn, _e, _n))
+                for (fn, e), n in zip(_entries, taken)
             ]
 
             def step(carry, op):

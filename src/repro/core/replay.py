@@ -59,7 +59,7 @@ from repro.core.events import Event, METRIC_NAMES, N_METRICS, is_comm
 from repro.core.noise import (FidelityDistribution, NoiseConfig,  # noqa: F401
                               parse_fidelity_csv)
 from repro.core.tracer import trace_fn
-from repro.sharding.collectives import DeviceComm, LocalSim
+from repro.sharding.collectives import DeviceComm, LocalSim, runs
 
 #: Exponents up to this unroll at trace time; above it ``rep`` emits a
 #: rolled ``fori_loop`` (one body trace regardless of n).  Shared with the
@@ -74,7 +74,8 @@ def rep(fn, n: int, st: dict, comm) -> dict:
         for _ in range(n):
             st = fn(st, comm)
         return st
-    return lax.fori_loop(0, n, lambda i, s: fn(s, comm), st)
+    with runs(n):
+        return lax.fori_loop(0, n, lambda i, s: fn(s, comm), st)
 
 
 def load_saved_module(path, name: str | None = None):
@@ -302,6 +303,7 @@ class ProxyProgram:
         self._compiled_batched: dict = {}  # (sig, comm, n, shapes) -> vmapped fn
         self._metrics_cache: dict = {}     # (sig, shapes) -> np.ndarray
         self._mesh_comms: dict = {}        # placement key -> DeviceComm
+        self._mesh_unrun: set = set()      # mesh executables not yet dispatched
         self._submeshes: dict = {}         # (mesh id, placement key) -> Mesh
         self._sig_by_rank: dict | None = None
         self._shapes_key_cache = None      # filled by _shapes_key()
@@ -532,6 +534,7 @@ class ProxyProgram:
                 traced, mesh=submesh, in_specs=(spec,), out_specs=spec,
                 check_vma=False))
             self._compiled_batched[key] = fn
+            self._mesh_unrun.add(fn)
         else:
             self._counters["batch_cache_hits"] += 1
         return fn
@@ -711,11 +714,21 @@ class ProxyProgram:
                       noise: "NoiseConfig | None" = None) -> dict[int, dict]:
         """Mesh-sharded sweep body: dispatch every placed group first (jax
         dispatch is asynchronous — groups on disjoint device subsets overlap),
-        gather/unstack after, block once at the end."""
+        gather/unstack after, block once at the end.
+
+        A group's first dispatch, which traces, lowers and compiles its
+        executable, is the span ``proxy.mesh_first``; the collectives
+        lowered into it count under it (``replay.collectives``)."""
         pending = []
         for fn, arg, grp, stacked in self._group_work_mesh(
                 ranks, seed, per_rank_seeds, mesh, batched, noise):
-            pending.append((fn(arg), grp, stacked))
+            if fn in self._mesh_unrun:
+                self._mesh_unrun.discard(fn)
+                with obs.span("proxy.mesh_first", root=self.root or None):
+                    res = fn(arg)
+            else:
+                res = fn(arg)
+            pending.append((res, grp, stacked))
         out: dict[int, dict] = {}
         for res, grp, stacked in pending:
             if stacked:

@@ -23,14 +23,39 @@ buffer (mean/slice), so proxy state is a stable pytree through ``fori_loop``.
 """
 from __future__ import annotations
 
+import contextlib
+import threading
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.core.events import CommEvent, decode_relative_perm
 from repro.core import tracer as _tracer
+
+_RUNS = threading.local()
+
+
+def traced_runs() -> int:
+    """How often the code being traced now runs in one call of its
+    executable: the product of the trip counts :func:`runs` declared
+    around it (1 outside them)."""
+    return getattr(_RUNS, "n", 1)
+
+
+@contextlib.contextmanager
+def runs(n: int):
+    """Trace the enclosed code, which the executable runs ``n`` times for
+    each run of the code around it: a rolled loop's body, a switch branch
+    taken ``n`` times."""
+    outer = traced_runs()
+    _RUNS.n = outer * int(n)
+    try:
+        yield
+    finally:
+        _RUNS.n = outer
 
 
 # ---------------------------------------------------------------------------
@@ -142,12 +167,17 @@ class DeviceComm:
     is audited for this (see :func:`repro.compat.collective_batching_audit`
     and tests/test_replay_mesh.py: batched-vs-sequential replay is
     bit-identical for every kind).
+
+    Each collective ``do`` lowers adds, at trace time, how often the
+    executable runs it (:func:`traced_runs`) to the counter
+    ``replay.collectives``.
     """
 
     def __init__(self, axis_sizes: dict[str, int]):
         self.axis_sizes = dict(axis_sizes)
 
     def do(self, st: dict, buf: str, *, kind: str, axes, detail, shape, dtype):
+        obs.count("replay.collectives", traced_runs())
         st = dict(st)
         x = st[buf].astype(dtype).reshape(shape)
         ax = axes if len(axes) > 1 else axes[0]

@@ -90,6 +90,20 @@ def test_proxy_group_executable_compiles(one_chip, mamba_decode):
     assert 0 < _device_bytes(compiled) < HBM_BYTES
 
 
+def test_npb_mg_class_c_solve_fits_four_chips(topo):
+    """NPB MG's class C solve over the 1 x 2 x 2 rank grid of the
+    benchmark's cell: it fits each chip, and its halos are collectives."""
+    from repro.compat import make_mesh
+    from repro.models import npb_mg
+    mesh = make_mesh((2, 2), ("z", "y"), devices=topo.devices[:4])
+    n = npb_mg.CLASSES["C"]["n"]
+    v = jax.ShapeDtypeStruct((n, n, n), jnp.float32,
+                             sharding=npb_mg.sharding(mesh))
+    compiled = npb_mg.build("C", mesh).lower(v).compile()
+    assert 0 < _device_bytes(compiled) < HBM_BYTES
+    assert "collective-permute" in compiled.as_text()
+
+
 def test_pgd_solver_compiles(one_chip):
     from repro.core import proxy_search
 
