@@ -101,6 +101,17 @@ def _row_key(rows: int) -> str:
     return f"rows{rows}"
 
 
+def row_key(unroll: int) -> str:
+    """State key of the buffer a ``move_shift`` turn at ``unroll`` runs
+    over."""
+    return _row_key(row_count(unroll))
+
+
+def is_row_key(key: str) -> bool:
+    """Whether state key ``key`` names a ``move_shift`` row buffer."""
+    return key.startswith("rows") and key[4:].isdigit()
+
+
 def row_unrolls(combos: Iterable) -> tuple[int, ...]:
     """The unrolls of the ``(x, unroll)`` combos that run ``move_shift``:
     what :func:`init_state` sizes the row buffers from."""

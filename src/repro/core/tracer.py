@@ -88,11 +88,12 @@ class JaxprWalker:
     ``exact_cond=True`` switches on constant-propagated control-flow
     resolution: jaxpr constants (and scan-carried constants / per-iteration
     xs slices) flow through an environment, ``cond`` equations with a
-    resolved scalar index walk **only the selected branch**, and equations
-    whose inputs are fully constant fold to zero cost (they are program-
-    counter bookkeeping — e.g. the ``clamp`` a ``lax.switch`` inserts — not
-    workload).  This is how grammar-compiled proxy modules (scan-over-
-    opcodes + switch dispatch, :mod:`repro.core.progtable`) measure
+    resolved scalar index walk **only the selected branch**, a
+    ``fori_loop`` whose bound folds walks its body that many times, and
+    equations whose inputs are fully constant fold to zero cost (they are
+    program-counter bookkeeping — e.g. the ``clamp`` a ``lax.switch``
+    inserts — not workload).  This is how grammar-compiled proxy modules
+    (scan-over-opcodes + switch dispatch, :mod:`repro.core.progtable`) measure
     bit-identically to the unrolled reference.  Default off: original-
     program traces (which may use data-dependent ``lax.cond``) keep the
     legacy branch-0 / max-cost semantics, so fidelity baselines are
@@ -232,7 +233,7 @@ class JaxprWalker:
             self._walk_scan(eqn, env)
             return
         if name == "while":
-            self._walk_while(eqn)
+            self._walk_while(eqn, env)
             return
         if name == "cond":
             self._walk_cond(eqn, env)
@@ -317,9 +318,29 @@ class JaxprWalker:
             vec += e.vector
         return vec
 
-    def _walk_while(self, eqn) -> None:
+    def _walk_while(self, eqn, env: dict | None = None) -> None:
         body = eqn.params["body_jaxpr"]
         cond = eqn.params["cond_jaxpr"]
+        trips = self._fori_trips(eqn, env) if self.exact_cond else None
+        if trips is not None:
+            # a fori_loop whose bound folds from constants (a program
+            # table's row loop) dispatches ``trips`` runs of its body, as a
+            # switch step dispatches its branch: the counter and its test
+            # are the table's bookkeeping and free, the body is costed whole
+            nc = int(eqn.params["cond_nconsts"])
+            nb = int(eqn.params["body_nconsts"])
+            sub = list(eqn.invars[nc:nc + nb + 1]) + [None] * (
+                len(eqn.invars) - nc - nb - 1)
+            if _contains_collective(body):
+                for _ in range(trips):
+                    self._walk_sub(body, sub, env)
+                return
+            w = JaxprWalker(self.axis_sizes, exact_cond=True)
+            w._walk_sub(body, sub, env)
+            w.flush()
+            for e in w.events:
+                self.pending += e.vector * trips
+            return
         # trip count is dynamic; cost one iteration and flag serialization.
         if _contains_collective(body):
             self.walk(cond)
@@ -327,6 +348,37 @@ class JaxprWalker:
         else:
             self.pending += _subtree_cost(cond) + _subtree_cost(body)
             self.pending[I_SCAN] += 1
+
+    def _fori_trips(self, eqn, env) -> int | None:
+        """Trip count of a ``fori_loop`` lowered to a ``while`` (carries
+        ``i, upper, ...``; cond ``i < upper``; body ``i + 1``, ``upper``
+        unchanged, ``i`` read by nothing else) whose bounds are known in
+        ``env``; None for any other loop."""
+        nc = int(eqn.params["cond_nconsts"])
+        nb = int(eqn.params["body_nconsts"])
+        cond = eqn.params["cond_jaxpr"].jaxpr
+        body = eqn.params["body_jaxpr"].jaxpr
+        if len(cond.eqns) != 1 or cond.eqns[0].primitive.name != "lt":
+            return None
+        cvars = cond.invars[nc:]
+        if list(cond.eqns[0].invars) != cvars[:2] \
+                or cond.outvars[0] is not cond.eqns[0].outvars[0]:
+            return None
+        i_in, up_in = body.invars[nb], body.invars[nb + 1]
+        i_out, up_out = body.outvars[0], body.outvars[1]
+        step = [q for q in body.eqns if q.outvars[0] is i_out]
+        uses = [q for q in body.eqns if any(v is i_in for v in q.invars)]
+        if up_out is not up_in or len(step) != 1 or uses != step \
+                or step[0].primitive.name != "add" \
+                or step[0].invars[0] is not i_in \
+                or not isinstance(step[0].invars[1], _Literal) \
+                or int(step[0].invars[1].val) != 1:
+            return None
+        lo = self._val(eqn.invars[nc + nb], env)
+        hi = self._val(eqn.invars[nc + nb + 1], env)
+        if lo is None or hi is None or np.ndim(lo) or np.ndim(hi):
+            return None
+        return max(int(hi) - int(lo), 0)
 
     def _walk_cond(self, eqn, env: dict | None = None) -> None:
         branches = eqn.params["branches"]

@@ -104,6 +104,56 @@ def test_npb_mg_class_c_solve_fits_four_chips(topo):
     assert "collective-permute" in compiled.as_text()
 
 
+def _branch_row_copies(hlo: str) -> list[str]:
+    """Copies of a ``move_shift`` row buffer (``f32[rows,32768]``) inside
+    the computations a conditional lists as its branches."""
+    import re
+    names = set()
+    for m in re.finditer(r"branch_computations=\{([^}]*)\}", hlo):
+        names |= {n.strip().lstrip("%") for n in m.group(1).split(",")}
+    found, comp = [], None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            comp = head.group(1)
+        elif comp in names and re.search(
+                r"f32\[\d+,32768\]\{[^}]*\} copy\(", line):
+            found.append(line.strip())
+    return found
+
+
+def test_program_table_switch_copies_no_row_buffer(one_chip):
+    """A switched program table (a comm terminal, a compute terminal
+    without ``move_shift``, ``move_shift`` at unrolls 8 and 1024, the
+    latter past the 512-row cap) compiles with no row-buffer copy in any
+    conditional branch: the row buffers stay out of the switch."""
+    from repro.core import blocks
+    from repro.core.progtable import ProgramTable
+    from repro.sharding.collectives import LocalSim
+
+    psum = dict(kind="psum", shape=(16,), dtype="float32", axes=("x",),
+                detail=())
+    terms = (("compute", (0, 0, 1, 0, 0, 0, 0, 0, 3, 0, 5), 8),
+             ("compute", (0, 1, 0, 0, 0, 0, 0, 0, 0, 0, 1), 1),
+             ("comm", "buf0", psum),
+             ("compute", (0, 0, 0, 0, 0, 0, 0, 0, 2, 0, 2), 1024))
+    prog = (("t", 0, 1), ("t", 2, 1), ("t", 1, 1), ("t", 3, 1), ("t", 2, 1),
+            ("t", 0, 3), ("t", 1, 1), ("t", 2, 1))
+    pt = ProgramTable(terms, {}, [prog])
+
+    def state():
+        st = blocks.init_state(0, (8, 1024))
+        st["buf0"] = jnp.zeros((16,), jnp.float32)
+        return st
+
+    st = _on(one_chip, jax.eval_shape(state))
+    hlo = jax.jit(lambda s: pt.run(0, s, LocalSim()),
+                  donate_argnums=0).lower(st).compile().as_text()
+    assert "branch_computations" in hlo
+    assert {"rows8", "rows512"} <= set(st)
+    assert _branch_row_copies(hlo) == []
+
+
 def test_pgd_solver_compiles(one_chip):
     from repro.core import proxy_search
 
